@@ -11,6 +11,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.runtime import enable_compile_cache
+
 from . import (
     bench_ablation,
     bench_accuracy_sparsity,
@@ -45,6 +47,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true", help="reduced repeats/steps")
     ap.add_argument("--only", default=None, help="run one benchmark by name")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(BENCHES)
     failures = []

@@ -232,7 +232,7 @@ def kwarg(call: ast.Call, name: str) -> ast.AST | None:
 
 
 _JIT_NAMES = {"jax.jit", "jit", "pjit", "jax.pjit"}
-_SHARD_MAP_NAMES = {"shard_map", "jax.experimental.shard_map.shard_map"}
+_SHARD_MAP_NAMES = {"shard_map", "jax.shard_map"}
 
 
 def is_jit_ref(node: ast.AST) -> bool:

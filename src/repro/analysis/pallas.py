@@ -11,9 +11,10 @@ the kernel wrappers from model/serve code) verify, statically:
 - PL403: a grid computed with ``//`` has a divisibility guard (some ``%``
   check) in the enclosing function — silent shape truncation otherwise;
 - PL404: ``interpret=`` at kernel entry points routes through
-  ``KernelPolicy.interpret`` (a name or ``*.interpret`` attribute), never an
-  ad-hoc literal / ``not on_tpu()`` expression — PR 6's silent-fallback class:
-  per-call booleans drift apart from the policy the engine actually built.
+  ``KernelPolicy.interpret`` (a name or ``*.interpret`` attribute) or the one
+  platform resolver ``resolve_interpret(<name>)``, never an ad-hoc literal /
+  ``not on_tpu()`` expression — PR 6's silent-fallback class: per-call
+  booleans drift apart from the policy the engine actually built.
 """
 from __future__ import annotations
 
@@ -144,13 +145,17 @@ def _has_mod_guard(fn: ast.AST) -> bool:
 
 
 def _interpret_ok(value: ast.AST, env: dict[str, list[ast.AST]]) -> bool:
-    """interpret= must be a policy-routed value: a bare parameter name or an
-    attribute chain ending in ``.interpret``.  Literals and computed
-    expressions (``not on_tpu()``) are ad-hoc — including laundering through a
-    local variable assigned from one."""
+    """interpret= must be a policy-routed value: a bare parameter name, an
+    attribute chain ending in ``.interpret``, or ``resolve_interpret`` (the
+    platform default's one owner) applied to one of those.  Literals and
+    computed expressions (``not on_tpu()``) are ad-hoc — including laundering
+    through a local variable assigned from one."""
     resolved = _resolve(value, env)
     if resolved is None:
         resolved = value
+    if (isinstance(resolved, ast.Call) and last_segment(call_name(resolved)) == "resolve_interpret"
+            and len(resolved.args) == 1 and not resolved.keywords):
+        return _interpret_ok(resolved.args[0], env)
     if isinstance(resolved, ast.Attribute) and resolved.attr == "interpret":
         return True
     if isinstance(resolved, ast.Name):

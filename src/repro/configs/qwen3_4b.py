@@ -1,4 +1,4 @@
-"""qwen3-4b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-8B; hf]."""
+"""qwen3-4b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-4B; hf]."""
 from .base import ModelConfig
 
 

@@ -36,7 +36,19 @@ from repro.core.dynatran import SITES, SparsityConfig, prune_
 
 Array = jax.Array
 
-__all__ = ["KernelPolicy", "derive_draft_policy", "resolve_policy"]
+__all__ = ["KernelPolicy", "derive_draft_policy", "on_tpu", "resolve_interpret", "resolve_policy"]
+
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place the Pallas interpret flag defaults from the platform:
+    ``None`` means compiled kernels on TPU and the Pallas interpreter (a CPU
+    emulation) elsewhere; an explicit bool is kept as given."""
+    return (not on_tpu()) if interpret is None else bool(interpret)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -44,8 +56,7 @@ __all__ = ["KernelPolicy", "derive_draft_policy", "resolve_policy"]
 class KernelPolicy:
     """How kernels execute: backend selection + dynamic-sparsity contract.
 
-    backend:   "ref" (XLA reference ops) or "pallas" (fused kernels;
-               interpret-mode off-TPU).
+    backend:   "ref" (XLA reference ops) or "pallas" (fused kernels).
     mode:      sparsity mode — "none", "dynatran" or "topk" (mirrors
                ``SparsityConfig.mode``).
     sites:     which tensor classes are pruned at runtime (subset of
@@ -60,7 +71,9 @@ class KernelPolicy:
                every tile — the exact-parity "masked" reference for the
                skipping path (identical lowering, identical bits).
     topk_k:    k for the top-k attention baseline.
-    interpret: run Pallas kernels in interpret mode (CPU emulation).
+    interpret: run Pallas kernels in interpret mode (CPU emulation);
+               ``None`` resolves from the platform at construction
+               (:func:`resolve_interpret`), so it is always a bool.
     taus:      per-site thresholds (runtime leaves; None when inactive).
     """
 
@@ -70,7 +83,7 @@ class KernelPolicy:
     block: int = 128
     skip: bool | None = None
     topk_k: int = 64
-    interpret: bool = True
+    interpret: bool | None = None
     taus: Any = None
 
     def __post_init__(self):
@@ -84,6 +97,7 @@ class KernelPolicy:
         if unknown:
             raise ValueError(f"unknown sparsity sites {unknown}")
         self.sites = tuple(self.sites)
+        self.interpret = resolve_interpret(self.interpret)
 
     # -- pytree protocol: taus are leaves, everything else is treedef --------
     def tree_flatten(self):
@@ -111,7 +125,7 @@ class KernelPolicy:
         *,
         backend: str = "ref",
         skip: bool | None = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
     ) -> "KernelPolicy":
         """Lift a model-level ``SparsityConfig`` (+ resolved taus) into a policy."""
         sp = sparsity if sparsity is not None else SparsityConfig()
@@ -203,7 +217,7 @@ def resolve_policy(
     taus: Mapping[str, Any] | None | object = _SENTINEL,
     use_pallas: bool | None | object = _SENTINEL,
     default_sparsity: SparsityConfig | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> KernelPolicy:
     """The one deprecation adapter from the legacy kwargs to ``KernelPolicy``.
 

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.policy import resolve_interpret
+
 DEFAULT_BLOCK = (128, 128, 128)  # bm, bk, bn — MXU 128-aligned
 
 
@@ -65,7 +67,7 @@ def block_sparse_matmul(
     dataflow: str = "ijk",  # "ijk" (k innermost, paper's [b,i,j,k]) | "kij"
     skip: bool = True,  # False = execute every tile (mask-only exact reference)
     with_visits: bool = False,  # also return the number of tile MACs issued
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array | tuple[jax.Array, jax.Array]:
     m, k = x.shape
     k2, n = w.shape
@@ -112,5 +114,5 @@ def block_sparse_matmul(
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x_tile_mask, w_tile_mask, x, w)

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.policy import resolve_interpret
+
 DEFAULT_BLOCK = (256, 128)
 
 
@@ -30,7 +32,7 @@ def _kernel(x_ref, tau_ref, out_ref, tile_mask_ref):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def dynatran_prune(
-    x: jax.Array, tau: jax.Array | float, *, block: tuple[int, int] = DEFAULT_BLOCK, interpret: bool = True
+    x: jax.Array, tau: jax.Array | float, *, block: tuple[int, int] = DEFAULT_BLOCK, interpret: bool | None = None
 ) -> tuple[jax.Array, jax.Array]:
     """Prune a [M, N] (or [..., M, N], flattened) matrix; returns
     (pruned, tile_mask [M/bm, N/bn] bool)."""
@@ -59,6 +61,6 @@ def dynatran_prune(
             jax.ShapeDtypeStruct((m, n), x.dtype),
             jax.ShapeDtypeStruct(grid, jnp.bool_),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, tau_arr)
     return out.reshape(orig_shape), tile_mask

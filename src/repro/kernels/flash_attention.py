@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.policy import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -90,7 +92,7 @@ def flash_attention(
     prune_tau: jax.Array | float = 0.0,  # DynaTran attn-prob threshold (runtime input, no recompile)
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, skv, hk, _ = k.shape
@@ -122,6 +124,6 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qr, kr, vr, tau_arr)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

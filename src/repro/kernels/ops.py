@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.dynatran import block_mask
-from repro.core.policy import KernelPolicy, resolve_policy
+from repro.core.policy import KernelPolicy, on_tpu, resolve_policy
 
 from . import ref
 from .block_sparse_matmul import block_sparse_matmul
@@ -36,18 +36,12 @@ __all__ = [
 ]
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def _platform_policy() -> KernelPolicy:
     """The historical backend-by-platform default as a policy: fused kernels
-    compiled on TPU, reference path (with interpret-mode emulation available)
-    elsewhere.  This is the one sanctioned construction site for the
-    platform-derived ``interpret`` flag — kernel call sites must route
-    ``interpret=pol.interpret`` (reprolint PL404)."""
-    tpu = on_tpu()
-    return KernelPolicy(backend="pallas" if tpu else "ref", interpret=not tpu)
+    compiled on TPU, reference path elsewhere.  The ``interpret`` flag comes
+    from the platform through ``KernelPolicy`` itself; kernel call sites
+    route ``interpret=pol.interpret`` (reprolint PL404)."""
+    return KernelPolicy(backend="pallas" if on_tpu() else "ref")
 
 
 def prune(x, tau, *, policy=None, **kw):

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.policy import resolve_interpret
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, C, N):
     ci = pl.program_id(1)
@@ -70,7 +72,7 @@ def wkv6_chunked(
     u: jax.Array,  # [H, N]
     *,
     chunk: int = 32,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     B, S, H, N = r.shape
     C = min(chunk, S)
@@ -93,6 +95,6 @@ def wkv6_chunked(
         out_specs=pl.BlockSpec((1, C, N), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, N), r.dtype),
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rr, kk, vv, ww, uu)
     return out.reshape(B, H, S, N).transpose(0, 2, 1, 3)

@@ -49,10 +49,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, cfg_override=None, s
     t_compile = time.time() - t0
 
     mem = compiled.memory_analysis()
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jaxlib returns a per-device list
-        ca = ca[0] if ca else {}
-    ca = ca or {}
+    ca = compiled.cost_analysis() or {}
     txt = compiled.as_text()
 
     # trip-count correction: scan bodies are visited once by cost analysis

@@ -7,22 +7,27 @@ Single pod:  (16, 16)    axes ("data", "model")  — v5e-256
 Multi-pod:   (2, 16, 16) axes ("pod", "data", "model") — 2 pods / 512 chips.
 "pod" is pure data-parallel (one cross-pod gradient all-reduce per step);
 "data" is FSDP (batch + weight shards); "model" is tensor/expert-parallel.
+
+Every axis is ``Auto``: the step builders place data with
+``with_sharding_constraint`` and ``NamedSharding``, which need Auto axes
+(``jax.make_mesh`` defaults to Explicit ones).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Degenerate 1x1 mesh over whatever devices exist (CPU tests)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serve_mesh(tp: int) -> jax.sharding.Mesh:
@@ -41,7 +46,9 @@ def make_serve_mesh(tp: int) -> jax.sharding.Mesh:
             f"tp={tp} needs {tp} devices but only {len(devices)} are visible "
             "(emulate with XLA_FLAGS=--xla_force_host_platform_device_count=N)"
         )
-    return jax.make_mesh((1, tp), ("data", "model"), devices=devices[:tp])
+    return jax.make_mesh(
+        (1, tp), ("data", "model"), devices=devices[:tp], axis_types=(AxisType.Auto,) * 2
+    )
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

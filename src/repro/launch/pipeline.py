@@ -26,7 +26,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core.policy import KernelPolicy
-from repro.launch.sharding import SHARD_MAP_NO_CHECK as _SHARD_MAP_NO_CHECK, shard_map as _shard_map
 from repro.models import transformer, zoo
 
 Array = jax.Array
@@ -98,11 +97,11 @@ def make_pipeline_forward(cfg: ModelConfig, mesh: Mesh, n_micro: int, axis: str 
             h = h * jnp.sqrt(float(cfg.d_model)).astype(h.dtype)
 
         shard = functools.partial(
-            _shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(P(axis), P()),
             out_specs=P(),
-            **_SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         out = shard(per_stage)(params["blocks"], h)
         _, norm = transformer.make_norm(cfg.norm)

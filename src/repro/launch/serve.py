@@ -37,6 +37,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.runtime import device_info, enable_compile_cache
 from repro.models import zoo
 from repro.serve.engine import ContinuousServeConfig, ContinuousServeEngine, ServeConfig, ServeEngine
 from repro.serve.sampling import SamplingParams
@@ -91,7 +92,8 @@ def main() -> None:
     ap.add_argument("--page-size", type=int, default=16, help="[continuous] tokens per KV page")
     ap.add_argument("--prefill-chunk", type=int, default=16, help="[continuous] prompt tokens per prefill call")
     ap.add_argument("--tp", type=int, default=1, help="[continuous] tensor-parallel shards over the mesh 'model' axis")
-    ap.add_argument("--use-pallas", action="store_true", help="[continuous] fused Pallas kernels (interpret mode off-TPU)")
+    ap.add_argument("--use-pallas", action="store_true",
+                    help="[continuous] fused Pallas paged-attention kernel instead of the XLA ref path")
     ap.add_argument(
         "--tile-skip", default=None, choices=["on", "off"],
         help="[continuous] tiled DynaTran datapath: 'on' skips all-dead KV/FFN "
@@ -132,6 +134,7 @@ def main() -> None:
                          "prefill; 0 disables the tier (every re-admission replays)")
     ap.add_argument("--kv-cache", default=None, choices=["bfloat16", "int8"], help="KV cache dtype override")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     try:
@@ -157,6 +160,8 @@ def main() -> None:
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, cfg.vocab, size=args.prompt_len).tolist() for _ in range(args.prompts)]
     req_inputs = [_synth_inputs(cfg, bundle, rng) for _ in range(args.prompts)]
+    dev = device_info()
+    print(f"[serve] device: platform={dev['platform']} kind={dev['kind']} count={dev['count']}")
     t0 = time.perf_counter()
     if args.continuous:
         scfg = ContinuousServeConfig(

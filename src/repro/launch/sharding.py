@@ -32,7 +32,6 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-import inspect
 import re
 from typing import Any
 
@@ -41,21 +40,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
-
-# jax >= 0.5 promotes shard_map to jax.shard_map and later renames
-# check_rep -> check_vma; probe the signature rather than the version.
-# (Shared by pipeline parallelism and the tensor-parallel serve path.)
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
-SHARD_MAP_NO_CHECK = (
-    {"check_vma": False}
-    if "check_vma" in inspect.signature(shard_map).parameters
-    else {"check_rep": False}
-)
-
 
 # ---------------------------------------------------------------------------
 # fitter

@@ -114,10 +114,10 @@ def init_params(key: Array, cfg: ModelConfig) -> dict:
         cks = jax.random.split(ck, cfg.pattern_len)
         return {str(i): _block_init(cks[i], cfg, pat, dtype) for i, pat in enumerate(cfg.attention_pattern)}
 
+    # stacked cycles (leading axis n_cycles on every block leaf), built in one
+    # jitted vmap: the device never holds per-cycle arrays next to the stack
     cycle_keys = jax.random.split(kblocks, cfg.n_cycles)
-    # stack cycles: leading axis n_cycles on every block leaf
-    cycles = [one_cycle(ck) for ck in cycle_keys]
-    params["blocks"] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cycles)
+    params["blocks"] = jax.jit(jax.vmap(one_cycle))(cycle_keys)
     return params
 
 
@@ -1047,7 +1047,7 @@ def make_tp_paged_fns(
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.launch.sharding import SHARD_MAP_NO_CHECK, paged_pool_specs, shard_map
+    from repro.launch.sharding import paged_pool_specs
 
     n = mesh.shape[axis]
     check_tp_support(cfg, n)
@@ -1073,11 +1073,11 @@ def make_tp_paged_fns(
                 occupancy=occupancy, ssm=ssm, live=live, policy=_pol(policy), tp=tp,
             )
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), specs, P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(), specs, P(), P()),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return f(params, pools, occupancy, tables, length, tokens, ssm, live, policy)
 
@@ -1090,11 +1090,11 @@ def make_tp_paged_fns(
                 occupancy=occupancy, ssm=ssm, fresh=fresh, policy=_pol(policy), tp=tp,
             )
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), specs, P(), P(), P(), P(), P(), P(), P(), P()),
             out_specs=(P(), specs, P(), P()),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return f(params, pools, occupancy, tables, start, tokens, n_valid, ssm, fresh, policy)
 
@@ -1104,9 +1104,9 @@ def make_tp_paged_fns(
         def body(pools, occupancy, src, dst):
             return paged_copy_pages(layout, pools, kind, src, dst, occupancy=occupancy)
 
-        f = shard_map(
+        f = jax.shard_map(
             body, mesh=mesh, in_specs=(specs, P(), P(), P()), out_specs=(specs, P()),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         return f(pools, occupancy, src, dst)
 

@@ -27,6 +27,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
 from repro.core.dynatran import SparsityConfig, ThresholdCalculator
@@ -108,7 +109,7 @@ class ServeEngine:
         self.policy = KernelPolicy.from_config(sp, self.taus)
 
         self._prefill = jax.jit(self._prefill_impl)
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(0,), static_argnames=("sample",))
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,), static_argnames=("sample",))
         self._sample = jax.jit(sample_tokens)
 
     # --- jitted bodies ----------------------------------------------------
@@ -136,8 +137,8 @@ class ServeEngine:
         last = logits[lengths - 1, jnp.arange(tokens.shape[0])]  # [B, V]
         return state, last
 
-    def _decode_impl(self, state, tokens, temps, top_ks, top_ps, seeds, steps, *, sample: bool):
-        logits, state = zoo.decode_step(self.params, self.cfg, state, tokens, policy=self.policy)
+    def _decode_impl(self, params, state, tokens, temps, top_ks, top_ps, seeds, steps, *, sample: bool):
+        logits, state = zoo.decode_step(params, self.cfg, state, tokens, policy=self.policy)
         sliced = logits[..., : self.cfg.vocab]
         if sample:  # shared keyed sampler (serve/sampling.py)
             next_tok = sample_tokens(sliced, temps, top_ks, top_ps, seeds, steps)
@@ -191,7 +192,7 @@ class ServeEngine:
             # so mutating a previously passed array would race the dispatch
             steps_t = np.full((B,), t, np.int32)
             state, nxt, _ = self._decode(
-                state, cur, st["temps"], st["top_ks"], st["top_ps"], st["seeds"],
+                self.params, state, cur, st["temps"], st["top_ks"], st["top_ps"], st["seeds"],
                 steps_t, sample=sample,
             )
             cur = nxt[:, None]
@@ -239,7 +240,7 @@ class ContinuousServeConfig:
     # amortises that host round-trip W-fold.  Rows finishing mid-window
     # waste at most W-1 row-steps (their surplus tokens are discarded).
     decode_window: int = 1
-    use_pallas: bool = False  # fused paged-attention kernel (interpret mode on CPU)
+    use_pallas: bool = False  # fused Pallas paged-attention kernel instead of the XLA ref path
     # DynaTran tile skipping in the hot kernels.  None (default) keeps the
     # legacy dense datapath (occupancy never allocated; old numerics,
     # bit-for-bit).  True routes decode attention + pruned FFN activations
@@ -441,6 +442,9 @@ class ContinuousServeEngine:
             self.fam.check_tp_support(cfg, self.mesh.shape["model"])
             # backend/skip ride the per-call KernelPolicy, not the TP closure
             self._tp_fns = self.fam.make_tp_paged_fns(cfg, self.layout, self.mesh)
+            # weights replicated over the mesh once, here: unplaced weights
+            # would be re-replicated into the shard_map on every dispatch
+            self.params = jax.device_put(params, NamedSharding(self.mesh, PartitionSpec()))
             if self.pools is not None:
                 paged_kind = next(k for k in self.bundle.kinds() if k.paged)
                 self.pools = jax.device_put(self.pools, state_shardings(paged_kind, self.pools, self.mesh))
@@ -494,14 +498,12 @@ class ContinuousServeEngine:
         self._sites = sp.sites
         # base kernel policy: static fields (backend/skip/sites) are fixed for
         # the engine's lifetime — only the taus leaves change per tick, so the
-        # runtime rho knob never recompiles the jitted steps
-        from repro.kernels.ops import on_tpu
-
+        # runtime rho knob never recompiles the jitted steps; ``interpret``
+        # comes from the platform (compiled kernels on TPU)
         self.policy = KernelPolicy.from_config(
             sp, None,
             backend="pallas" if scfg.use_pallas else "ref",
             skip=scfg.tile_skip,
-            interpret=not on_tpu(),
         )
         # per-page DynaTran occupancy side arrays (all-live at init) — only
         # materialised when the tiled datapath is on; None rides through the
@@ -513,8 +515,6 @@ class ContinuousServeEngine:
             else None
         )
         if self.occupancy is not None and self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec
-
             # bits are computed from the full pre-slice key: replicated
             self.occupancy = jax.device_put(
                 self.occupancy, NamedSharding(self.mesh, PartitionSpec())
@@ -535,19 +535,21 @@ class ContinuousServeEngine:
         self._fixed_rho = float(base_rho)
         self.current_rho = self._fixed_rho if self._dynatran else 0.0
 
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(0, 1, 2), static_argnames=("sample",))
-        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(0, 1, 2), static_argnames=("sample",))
+        # the weights enter every jitted step as an ARGUMENT: a closed-over
+        # array would be embedded in the compiled program as a constant
+        self._decode = jax.jit(self._decode_impl, donate_argnums=(1, 2, 3), static_argnames=("sample",))
+        self._prefill = jax.jit(self._prefill_impl, donate_argnums=(1, 2, 3), static_argnames=("sample",))
         # one fused dispatch per speculative tick: draft scan + verify scan
         # + device-side accept/rollback.  ``k`` is static (a depth change
         # recompiles, deliberately); the draft taus ride the draft policy's
         # runtime leaves, so moving ``draft_rho`` reuses this trace — the
         # trace-counter test pins both properties.
         self._spec = jax.jit(
-            self._spec_impl, donate_argnums=(0, 1, 2, 3), static_argnames=("sample", "k")
+            self._spec_impl, donate_argnums=(1, 2, 3, 4), static_argnames=("sample", "k")
         )
         self._draft_prefill = jax.jit(self._draft_prefill_impl, donate_argnums=(0,))
         self._copy = jax.jit(self._copy_impl, donate_argnums=(0, 1))
-        self._admit = jax.jit(self._admit_impl, donate_argnums=(0,))
+        self._admit = jax.jit(self._admit_impl, donate_argnums=(1,))
         # host-tier device halves: extract gathers whole pages for a spill
         # (pools NOT donated — the fetch must not invalidate them), insert
         # scatters a restored payload back (pools donated and rebound)
@@ -578,7 +580,7 @@ class ContinuousServeEngine:
 
     # --- jitted bodies ----------------------------------------------------
     def _decode_impl(
-        self, pools, ssm, occ, tables, lengths, tokens, live, policy,
+        self, params, pools, ssm, occ, tables, lengths, tokens, live, policy,
         temps, top_ks, top_ps, seeds, steps, *, sample: bool,
     ):
         """Scan ``decode_window`` steps per host round-trip; returns the
@@ -590,7 +592,7 @@ class ContinuousServeEngine:
         def body(carry, _):
             pools, ssm, occ, lengths, toks, stp = carry
             logits, pools, occ, ssm = self._step_decode(
-                pools, ssm, occ, tables, lengths, toks, live, policy
+                params, pools, ssm, occ, tables, lengths, toks, live, policy
             )
             sliced = logits[..., : self.cfg.vocab]
             if sample:
@@ -604,31 +606,31 @@ class ContinuousServeEngine:
         )
         return pools, ssm, occ, toks
 
-    def _step_decode(self, pools, ssm, occ, tables, lengths, tokens, live, policy):
+    def _step_decode(self, params, pools, ssm, occ, tables, lengths, tokens, live, policy):
         """One model step: the shard_map-wrapped TP path or the plain one.
         Returns ``(logits, pools, occupancy, ssm)`` — the uniform 4-tuple
         every family's paged step now speaks."""
         if self._tp_fns is not None:
             return self._tp_fns["decode"](
-                self.params, pools, occ, tables, lengths, tokens, ssm, live, policy
+                params, pools, occ, tables, lengths, tokens, ssm, live, policy
             )
         return self.fam.paged_decode_step(
-            self.params, self.cfg, self.layout, pools, tables, lengths, tokens,
+            params, self.cfg, self.layout, pools, tables, lengths, tokens,
             occupancy=occ, ssm=ssm, live=live, policy=policy,
         )
 
-    def _step_prefill(self, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy):
+    def _step_prefill(self, params, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy):
         if self._tp_fns is not None:
             return self._tp_fns["prefill"](
-                self.params, pools, occ, tables, start, tokens, n_valid, ssm, fresh, policy
+                params, pools, occ, tables, start, tokens, n_valid, ssm, fresh, policy
             )
         return self.fam.paged_prefill_chunk(
-            self.params, self.cfg, self.layout, pools, tables, start, tokens, n_valid,
+            params, self.cfg, self.layout, pools, tables, start, tokens, n_valid,
             occupancy=occ, ssm=ssm, fresh=fresh, policy=policy,
         )
 
     def _spec_impl(
-        self, pools, ssm, occ, dpools, tables, lengths, tokens, live, policy, draft_policy,
+        self, params, pools, ssm, occ, dpools, tables, lengths, tokens, live, policy, draft_policy,
         temps, top_ks, top_ps, seeds, steps, *, sample: bool, k: int,
     ):
         """One speculative tick, fused into a single dispatch: a draft scan
@@ -660,7 +662,7 @@ class ContinuousServeEngine:
             def dbody(carry, _):
                 p_, s_, o_, lens_, toks_, stp = carry
                 logits, p_, o_, s_ = self._step_decode(
-                    p_, s_, o_, tables, lens_, toks_, live, draft_policy
+                    params, p_, s_, o_, tables, lens_, toks_, live, draft_policy
                 )
                 sliced = logits[..., : self.cfg.vocab]
                 if sample:
@@ -704,7 +706,7 @@ class ContinuousServeEngine:
         def vbody(carry, tok_in):
             p_, s_, o_, lens_, stp = carry
             logits, p_, o_, s_ = self._step_decode(
-                p_, s_, o_, tables, lens_, tok_in[:, None], live, policy
+                params, p_, s_, o_, tables, lens_, tok_in[:, None], live, policy
             )
             sliced = logits[..., : self.cfg.vocab]
             if sample:
@@ -742,18 +744,18 @@ class ContinuousServeEngine:
         )
         return dpools
 
-    def _admit_impl(self, slot_state, slot, inputs, policy):
+    def _admit_impl(self, params, slot_state, slot, inputs, policy):
         """Admission-computed slot state (whisper: encoder cross-KV) — the
         family hook writes one slot row; ``slot`` is a traced scalar so
         every slot shares one trace."""
-        return self.fam.admit_slot(self.params, self.cfg, slot_state, slot, policy=policy, **inputs)
+        return self.fam.admit_slot(params, self.cfg, slot_state, slot, policy=policy, **inputs)
 
     def _prefill_impl(
-        self, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy,
+        self, params, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy,
         temps, top_ks, top_ps, seeds, *, sample: bool,
     ):
         logits, pools, occ, ssm = self._step_prefill(
-            pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy
+            params, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy
         )
         sliced = logits[..., : self.cfg.vocab]
         if sample:  # a request's FIRST token is sampled at step index 0
@@ -944,7 +946,9 @@ class ContinuousServeEngine:
             # after eviction recomputes the same bits, so replay is exact.
             for req in admitted:
                 dev_inputs = {k: jnp.asarray(v)[None] for k, v in req.inputs.items()}
-                self.slot_state = self._admit(self.slot_state, np.int32(req.slot), dev_inputs, policy)
+                self.slot_state = self._admit(
+                    self.params, self.slot_state, np.int32(req.slot), dev_inputs, policy
+                )
         prefill_reqs = self.sched.prefill_candidates()
         ready = self.sched.decode_rows()
         finished: list[Request] = []
@@ -1237,7 +1241,7 @@ class ContinuousServeEngine:
         self._drain_copies()
         tables = self._tables_for(reqs)
         self.pools, self.slot_state, self.occupancy, next_tok = self._prefill(
-            self.pools, self.slot_state, self.occupancy, tables,
+            self.params, self.pools, self.slot_state, self.occupancy, tables,
             jnp.asarray(starts), jnp.asarray(toks), jnp.asarray(nv), jnp.asarray(fresh),
             policy, st["temps"], st["top_ks"], st["top_ps"], st["seeds"], sample=sample,
         )
@@ -1294,7 +1298,7 @@ class ContinuousServeEngine:
             sample |= req.params.temperature > 0
         self._drain_copies()
         self.pools, self.slot_state, self.occupancy, win_tok = self._decode(
-            self.pools, self.slot_state, self.occupancy, self._tables_for(rows),
+            self.params, self.pools, self.slot_state, self.occupancy, self._tables_for(rows),
             jnp.asarray(lens), jnp.asarray(toks), jnp.asarray(live), policy,
             st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),
             sample=sample,
@@ -1347,7 +1351,7 @@ class ContinuousServeEngine:
         self._drain_copies()
         dpools = self._draft["pools"] if self._draft is not None else None
         self.pools, self.slot_state, self.occupancy, dpools, tgt_toks, m = self._spec(
-            self.pools, self.slot_state, self.occupancy, dpools, self._tables_for(rows),
+            self.params, self.pools, self.slot_state, self.occupancy, dpools, self._tables_for(rows),
             jnp.asarray(lens), jnp.asarray(toks), jnp.asarray(live),
             policy, self._draft_policy(policy),
             st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),

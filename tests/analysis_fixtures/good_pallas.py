@@ -2,6 +2,8 @@
 import jax
 from jax.experimental import pallas as pl
 
+from repro.core.policy import resolve_interpret
+
 
 def _body(x_ref, o_ref):
     o_ref[...] = x_ref[...]
@@ -20,4 +22,12 @@ def good_kernel(x, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,  # policy-routed: callers pass KernelPolicy.interpret
+    )(x)
+
+
+def good_kernel_platform_default(x, interpret=None):
+    return pl.pallas_call(
+        _body,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=resolve_interpret(interpret),  # the one platform resolver
     )(x)

@@ -84,6 +84,19 @@ class TestFixtures:
         p.write_text(bad)
         assert "PL404" not in codes(run_checks(paths=[p]))
 
+    @pytest.mark.parametrize(
+        "value, flagged",
+        [("resolve_interpret(interpret)", False), ("resolve_interpret(True)", True),
+         ("resolve_interpret(not on_tpu())", True)],
+    )
+    def test_platform_resolver_routes_only_policy_values(self, tmp_path, value, flagged):
+        src = (FIXTURES / "bad_pallas.py").read_text().replace(
+            "interpret=True,  # PL404", f"interpret={value},  # PL404"
+        )
+        p = tmp_path / "resolver.py"
+        p.write_text(src)
+        assert ("PL404" in codes(run_checks(paths=[p]))) is flagged
+
 
 class TestSelfRun:
     def test_repo_clean_against_committed_baseline(self):

@@ -79,6 +79,15 @@ class TestPytree:
             KernelPolicy(sites=("ffn_act", "nope"))
 
 
+class TestInterpretDefault:
+    def test_default_follows_platform(self):
+        """``interpret=None`` resolves at construction: the interpreter off
+        the TPU, compiled kernels on it; an explicit flag is kept."""
+        assert KernelPolicy().interpret is (jax.default_backend() != "tpu")
+        assert KernelPolicy(interpret=False).interpret is False
+        assert dataclasses.replace(KernelPolicy(), interpret=True).interpret is True
+
+
 class TestQueries:
     def test_wants_needs_mode_site_and_tau(self):
         sp = dynatran_sp(("ffn_act", "kv"))

@@ -10,10 +10,7 @@ import pytest
 
 def _flops(fn, *args):
     c = jax.jit(fn).lower(*args).compile()
-    ca = c.cost_analysis()
-    if isinstance(ca, list):  # older jaxlib returns a per-device list
-        ca = ca[0] if ca else {}
-    return float((ca or {}).get("flops", 0.0))
+    return float((c.cost_analysis() or {}).get("flops", 0.0))
 
 
 class TestScanBodyCounting:

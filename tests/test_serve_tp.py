@@ -183,6 +183,16 @@ class TestTPMemoryAndState:
             assert m["tp"] == tp
             assert m["cache_bytes_per_shard"] * tp == m["cache_bytes"]
 
+    def test_params_replicated_over_mesh(self, setup):
+        """The engine places the caller's single-device weights on the mesh
+        once, so no dispatch has to replicate them again."""
+        cfg, params, _ = setup
+        eng = make_engine(cfg, params, tp=2)
+        for leaf in jax.tree_util.tree_leaves(eng.params):
+            assert leaf.sharding.mesh == eng.mesh
+            assert leaf.sharding.is_fully_replicated
+            assert {s.device for s in leaf.addressable_shards} == set(eng.mesh.devices.flat)
+
     def test_int8_scale_pools_split_too(self, setup):
         cfg = dataclasses.replace(tiny_cfg(), kv_cache_dtype="int8")
         params = zoo.init_params(jax.random.PRNGKey(0), cfg)
@@ -464,7 +474,6 @@ class TestPallasKernelShardLocal:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.kernels.paged_attention import paged_gather
-        from repro.launch.sharding import SHARD_MAP_NO_CHECK, shard_map
 
         mesh = make_serve_mesh(2)
         rng = np.random.default_rng(0)
@@ -472,10 +481,10 @@ class TestPallasKernelShardLocal:
         table = np.array([[1, 2, 0], [3, 4, 5]], np.int32)
         want = paged_gather(jax.numpy.asarray(pool), jax.numpy.asarray(table))
         spec = P(None, None, "model", None)
-        f = shard_map(
+        f = jax.shard_map(
             lambda p, t: paged_gather(p, t), mesh=mesh,
             in_specs=(spec, P()), out_specs=P(None, None, "model", None),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         pool_s = jax.device_put(jax.numpy.asarray(pool), NamedSharding(mesh, spec))
         got = f(pool_s, jax.numpy.asarray(table))
@@ -485,7 +494,6 @@ class TestPallasKernelShardLocal:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.kernels.paged_attention import paged_decode_attention
-        from repro.launch.sharding import SHARD_MAP_NO_CHECK, shard_map
 
         mesh = make_serve_mesh(2)
         rng = np.random.default_rng(1)
@@ -499,12 +507,12 @@ class TestPallasKernelShardLocal:
             jax.numpy.asarray(table), jax.numpy.asarray(lengths),
         )
         pspec = P(None, None, "model", None)
-        f = shard_map(
+        f = jax.shard_map(
             lambda qq, kk, vv, tt, ll: paged_decode_attention(qq, kk, vv, tt, ll),
             mesh=mesh,
             in_specs=(P(None, None, "model", None), pspec, pspec, P(), P()),
             out_specs=P(None, None, "model", None),
-            **SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
         put = lambda x, s: jax.device_put(jax.numpy.asarray(x), NamedSharding(mesh, s))
         got = f(

@@ -16,7 +16,9 @@ from repro.models import zoo
 
 
 def host_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+
+    return make_host_mesh()
 
 
 class FakeMesh:
