@@ -691,7 +691,6 @@ class PrefixCache:
             "hit_rate": self.hits / self.lookups if self.lookups else 0.0,
             "pages_shared": self.pages_shared,
             "relinked_pages": self.relinked_pages,
-            "cached_pages": self.cached_pages,
             "host_spills": self.host_spills,
             "host_restores": self.host_restores,
         }

@@ -761,6 +761,33 @@ def _paged_attention(
     return attn.decode_attention(q, k_read, v_read, eff_len, window=None, logit_cap=cfg.attn_logit_cap)
 
 
+def _paged_block_tail(p: dict, cfg: ModelConfig, norm, hh: Array, attn_out: Array, pol: KernelPolicy) -> Array:
+    """A paged step's block after its attention output projection: the
+    residual and norms under ``proj``, the feed-forward under ``mlp``."""
+    with jax.named_scope("proj"):
+        if cfg.post_norms:
+            attn_out = norm(p["post_attn_norm"], attn_out)
+        hh = hh + attn_out
+        x = norm(p["ln2"], hh)
+    with jax.named_scope("mlp"):
+        mlp_out, _ = _mlp(p, cfg, x, pol)
+    with jax.named_scope("proj"):
+        if cfg.post_norms:
+            mlp_out = norm(p["post_mlp_norm"], mlp_out)
+        return hh + mlp_out
+
+
+def _paged_head(params: dict, cfg: ModelConfig, norm, h: Array) -> Array:
+    """Final norm and LM head of a paged step, under ``head``: [B, 1, D]
+    hidden states to [B, V] float32 logits."""
+    with jax.named_scope("head"):
+        h = norm(params["final_norm"], h)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = h @ head.astype(h.dtype)
+        logits = softcap(logits.astype(jnp.float32), cfg.final_logit_cap)
+        return constrain(logits[:, 0], "logits_2d")
+
+
 def paged_decode_step(
     params: dict,
     cfg: ModelConfig,
@@ -798,6 +825,11 @@ def paged_decode_step(
     slices q/k/v to its head block, runs scatter/gather/attention on the
     shard, and all-gathers the per-head attention outputs — bitwise-equal
     to the unsharded step.
+
+    Its ops carry named scopes, which a device trace reads back: ``proj``
+    (norms, q/k/v/o projections, rope), ``attn`` (K/V scatter, paged
+    attention, and the layer scan's slicing and write-back of the pools),
+    ``mlp`` and ``head`` (final norm, LM head).
     """
     pol = resolve_policy(policy, taus=taus, use_pallas=use_pallas, default_sparsity=cfg.sparsity)
     h = params["embed"][tokens]
@@ -818,25 +850,28 @@ def paged_decode_step(
             p = cycle_params[str(i)]
             table = tables[layout.slot_kinds[i]]
             ring = layout.slot_kinds[i] == "ring"
-            _x, q, k1, v1 = _qkv(p, cfg, hh, positions, None)
-            occ_i = None
-            if kv_site:
-                # liveness bit from the FULL key (pre-TP-slice: every shard
-                # computes the same replicated bit), scattered exactly where
-                # the key lands
-                bit = occupancy_bit(k1[:, 0], pol.tau("kv"))
-                op = scatter_token_ring if ring else scatter_token
-                occ_i = op(occ_c[str(i)], table, length, bit)
-                new_occ[str(i)] = occ_i
-            elif occupancy is not None:
-                new_occ[str(i)] = occ_c[str(i)]
-            q, k1, v1 = _tp_slice_heads(tp, q, k1, v1)
-            kcache = entry_scatter_token(kc[str(i)], table, length, k1[:, 0], ring=ring)
-            vcache = entry_scatter_token(vc[str(i)], table, length, v1[:, 0], ring=ring)
-            ao = _paged_attention(cfg, layout, i, q, kcache, vcache, table, length, pol=pol, occ=occ_i)
-            ao = _tp_gather_heads(tp, ao)
-            ao = pol.prune(ao, "attn_out")
-            attn_out = jnp.einsum("bshk,hkd->bsd", ao, p["wo"].astype(ao.dtype))
+            with jax.named_scope("proj"):
+                _x, q, k1, v1 = _qkv(p, cfg, hh, positions, None)
+            with jax.named_scope("attn"):
+                occ_i = None
+                if kv_site:
+                    # liveness bit from the FULL key (pre-TP-slice: every
+                    # shard computes the same replicated bit), scattered
+                    # exactly where the key lands
+                    bit = occupancy_bit(k1[:, 0], pol.tau("kv"))
+                    op = scatter_token_ring if ring else scatter_token
+                    occ_i = op(occ_c[str(i)], table, length, bit)
+                    new_occ[str(i)] = occ_i
+                elif occupancy is not None:
+                    new_occ[str(i)] = occ_c[str(i)]
+                q, k1, v1 = _tp_slice_heads(tp, q, k1, v1)
+                kcache = entry_scatter_token(kc[str(i)], table, length, k1[:, 0], ring=ring)
+                vcache = entry_scatter_token(vc[str(i)], table, length, v1[:, 0], ring=ring)
+                ao = _paged_attention(cfg, layout, i, q, kcache, vcache, table, length, pol=pol, occ=occ_i)
+                ao = _tp_gather_heads(tp, ao)
+                ao = pol.prune(ao, "attn_out")
+            with jax.named_scope("proj"):
+                attn_out = jnp.einsum("bshk,hkd->bsd", ao, p["wo"].astype(ao.dtype))
             if cfg.ssm_state:
                 ssm_out, s_new = ssm_mix(p["ssm"], norm(p["ssm_ln"], hh), state=ssmc[str(i)])
                 attn_out = (attn_out + ssm_out) * 0.5
@@ -845,13 +880,7 @@ def paged_decode_step(
                         lambda new, old: jnp.where(live[:, None, None], new, old), s_new, ssmc[str(i)]
                     )
                 new_ssm[str(i)] = s_new
-            if cfg.post_norms:
-                attn_out = norm(p["post_attn_norm"], attn_out)
-            hh = hh + attn_out
-            mlp_out, _ = _mlp(p, cfg, norm(p["ln2"], hh), pol)
-            if cfg.post_norms:
-                mlp_out = norm(p["post_mlp_norm"], mlp_out)
-            hh = hh + mlp_out
+            hh = _paged_block_tail(p, cfg, norm, hh, attn_out, pol)
             new_k[str(i)], new_v[str(i)] = kcache, vcache
         return hh, (new_k, new_v, new_occ if occupancy is not None else None,
                     new_ssm if cfg.ssm_state else None)
@@ -859,12 +888,11 @@ def paged_decode_step(
     xs = (params["blocks"], pools.k, pools.v,
           occupancy if occupancy is not None else jnp.zeros((cfg.n_cycles,)),
           ssm if cfg.ssm_state else jnp.zeros((cfg.n_cycles,)))
-    h, (ks, vs, occs, ssms) = jax.lax.scan(cycle_body, h, xs)
-    h = norm(params["final_norm"], h)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = h @ head.astype(h.dtype)
-    logits = softcap(logits.astype(jnp.float32), cfg.final_logit_cap)
-    logits = constrain(logits[:, 0], "logits_2d")
+    # the scan's per-layer slicing and write-back of the pools is K/V
+    # traffic: ``attn``, which the body's own scopes override for its ops
+    with jax.named_scope("attn"):
+        h, (ks, vs, occs, ssms) = jax.lax.scan(cycle_body, h, xs)
+    logits = _paged_head(params, cfg, norm, h)
     return (logits, PagedKV(k=ks, v=vs),
             occs if occupancy is not None else None,
             ssms if cfg.ssm_state else None)
@@ -903,7 +931,8 @@ def paged_prefill_chunk(
 
     When the policy's "kv" site is live, each cached key also records its
     occupancy bit (see ``paged_decode_step``); prefill only *writes* bits —
-    they are consumed by the decode attention.
+    they are consumed by the decode attention.  Named scopes as in
+    ``paged_decode_step``.
     """
     pol = resolve_policy(policy, taus=taus, default_sparsity=cfg.sparsity)
     b, c = tokens.shape
@@ -926,55 +955,58 @@ def paged_prefill_chunk(
             p = cycle_params[str(i)]
             table = tables[layout.slot_kinds[i]]
             ring = layout.slot_kinds[i] == "ring"
-            _x, q, k1, v1 = _qkv(p, cfg, hh, positions, None)
-            if kv_site:
-                bit = occupancy_bit(k1, pol.tau("kv"))  # [B, C], full pre-TP key
-                op = scatter_chunk_ring if ring else scatter_chunk
-                new_occ[str(i)] = op(occ_c[str(i)], table, start_len, bit, valid)
-            elif occupancy is not None:
-                new_occ[str(i)] = occ_c[str(i)]
-            q, k1, v1 = _tp_slice_heads(tp, q, k1, v1)
-            if ring and c > 1:
-                # sliding-window chunk: attend to the PRE-chunk ring context
-                # (explicit per-entry absolute positions — ring order is
-                # arbitrary) plus the chunk's own K/V, then commit the chunk.
-                # Ring capacity >= window guarantees every in-window prefix
-                # key is still present, for any chunk size.
-                k_ctx = entry_gather(kc[str(i)], table)
-                v_ctx = entry_gather(vc[str(i)], table)
-                ctx_pos = _ring_ctx_positions(start_len, layout.ring_capacity)
-                kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=True)
-                vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=True)
-                k_in, v_in = k1, v1
-                if isinstance(kc[str(i)], dict):
-                    # quantised cache: the in-chunk keys must carry the same
-                    # int8-round-tripped bits the pool (and every later
-                    # read) holds, or chunked prefill diverges from replay
-                    k_in = dequantize_kv(*quantize_kv(k1))
-                    v_in = dequantize_kv(*quantize_kv(v1))
-                ao = attn.ring_chunk_attention(
-                    q, k_ctx, v_ctx, ctx_pos, k_in, v_in, start_len, n_valid,
-                    window=layout.window, logit_cap=cfg.attn_logit_cap,
-                )
-            elif ring:
-                # C == 1: decode-style ring read — bitwise-identical to
-                # ``paged_decode_step`` (chunk_decode_attention at C == 1
-                # is bitwise decode_attention; the ring view enforces the
-                # window exactly as the dense ring buffer does)
-                kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=True)
-                vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=True)
-                k_read = entry_gather_ring(kcache, table, start_len, layout.window)
-                v_read = entry_gather_ring(vcache, table, start_len, layout.window)
-                ao = attn.chunk_decode_attention(q, k_read, v_read, start_len, logit_cap=cfg.attn_logit_cap)
-            else:
-                kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=False)
-                vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=False)
-                k_read = entry_gather(kcache, table)
-                v_read = entry_gather(vcache, table)
-                ao = attn.chunk_decode_attention(q, k_read, v_read, start_len, logit_cap=cfg.attn_logit_cap)
-            ao = _tp_gather_heads(tp, ao)
-            ao = pol.prune(ao, "attn_out")
-            attn_out = jnp.einsum("bshk,hkd->bsd", ao, p["wo"].astype(ao.dtype))
+            with jax.named_scope("proj"):
+                _x, q, k1, v1 = _qkv(p, cfg, hh, positions, None)
+            with jax.named_scope("attn"):
+                if kv_site:
+                    bit = occupancy_bit(k1, pol.tau("kv"))  # [B, C], full pre-TP key
+                    op = scatter_chunk_ring if ring else scatter_chunk
+                    new_occ[str(i)] = op(occ_c[str(i)], table, start_len, bit, valid)
+                elif occupancy is not None:
+                    new_occ[str(i)] = occ_c[str(i)]
+                q, k1, v1 = _tp_slice_heads(tp, q, k1, v1)
+                if ring and c > 1:
+                    # sliding-window chunk: attend to the PRE-chunk ring context
+                    # (explicit per-entry absolute positions — ring order is
+                    # arbitrary) plus the chunk's own K/V, then commit the chunk.
+                    # Ring capacity >= window guarantees every in-window prefix
+                    # key is still present, for any chunk size.
+                    k_ctx = entry_gather(kc[str(i)], table)
+                    v_ctx = entry_gather(vc[str(i)], table)
+                    ctx_pos = _ring_ctx_positions(start_len, layout.ring_capacity)
+                    kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=True)
+                    vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=True)
+                    k_in, v_in = k1, v1
+                    if isinstance(kc[str(i)], dict):
+                        # quantised cache: the in-chunk keys must carry the same
+                        # int8-round-tripped bits the pool (and every later
+                        # read) holds, or chunked prefill diverges from replay
+                        k_in = dequantize_kv(*quantize_kv(k1))
+                        v_in = dequantize_kv(*quantize_kv(v1))
+                    ao = attn.ring_chunk_attention(
+                        q, k_ctx, v_ctx, ctx_pos, k_in, v_in, start_len, n_valid,
+                        window=layout.window, logit_cap=cfg.attn_logit_cap,
+                    )
+                elif ring:
+                    # C == 1: decode-style ring read — bitwise-identical to
+                    # ``paged_decode_step`` (chunk_decode_attention at C == 1
+                    # is bitwise decode_attention; the ring view enforces the
+                    # window exactly as the dense ring buffer does)
+                    kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=True)
+                    vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=True)
+                    k_read = entry_gather_ring(kcache, table, start_len, layout.window)
+                    v_read = entry_gather_ring(vcache, table, start_len, layout.window)
+                    ao = attn.chunk_decode_attention(q, k_read, v_read, start_len, logit_cap=cfg.attn_logit_cap)
+                else:
+                    kcache = entry_scatter_chunk(kc[str(i)], table, start_len, k1, valid, ring=False)
+                    vcache = entry_scatter_chunk(vc[str(i)], table, start_len, v1, valid, ring=False)
+                    k_read = entry_gather(kcache, table)
+                    v_read = entry_gather(vcache, table)
+                    ao = attn.chunk_decode_attention(q, k_read, v_read, start_len, logit_cap=cfg.attn_logit_cap)
+                ao = _tp_gather_heads(tp, ao)
+                ao = pol.prune(ao, "attn_out")
+            with jax.named_scope("proj"):
+                attn_out = jnp.einsum("bshk,hkd->bsd", ao, p["wo"].astype(ao.dtype))
             if cfg.ssm_state:
                 sstate = ssmc[str(i)]
                 if fresh is not None:
@@ -984,13 +1016,7 @@ def paged_prefill_chunk(
                 ssm_out, s_new = ssm_mix(p["ssm"], norm(p["ssm_ln"], hh), state=sstate, n_valid=n_valid)
                 attn_out = (attn_out + ssm_out) * 0.5
                 new_ssm[str(i)] = s_new
-            if cfg.post_norms:
-                attn_out = norm(p["post_attn_norm"], attn_out)
-            hh = hh + attn_out
-            mlp_out, _ = _mlp(p, cfg, norm(p["ln2"], hh), pol)
-            if cfg.post_norms:
-                mlp_out = norm(p["post_mlp_norm"], mlp_out)
-            hh = hh + mlp_out
+            hh = _paged_block_tail(p, cfg, norm, hh, attn_out, pol)
             new_k[str(i)], new_v[str(i)] = kcache, vcache
         return hh, (new_k, new_v, new_occ if occupancy is not None else None,
                     new_ssm if cfg.ssm_state else None)
@@ -998,14 +1024,12 @@ def paged_prefill_chunk(
     xs = (params["blocks"], pools.k, pools.v,
           occupancy if occupancy is not None else jnp.zeros((cfg.n_cycles,)),
           ssm if cfg.ssm_state else jnp.zeros((cfg.n_cycles,)))
-    h, (ks, vs, occs, ssms) = jax.lax.scan(cycle_body, h, xs)
-    last = jnp.maximum(n_valid - 1, 0)[:, None, None]  # [B,1,1]
-    h = jnp.take_along_axis(h, last, axis=1)  # last valid position per row
-    h = norm(params["final_norm"], h)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = h @ head.astype(h.dtype)
-    logits = softcap(logits.astype(jnp.float32), cfg.final_logit_cap)
-    logits = constrain(logits[:, 0], "logits_2d")
+    with jax.named_scope("attn"):  # pool slicing and write-back, as in paged_decode_step
+        h, (ks, vs, occs, ssms) = jax.lax.scan(cycle_body, h, xs)
+    with jax.named_scope("head"):
+        last = jnp.maximum(n_valid - 1, 0)[:, None, None]  # [B,1,1]
+        h = jnp.take_along_axis(h, last, axis=1)  # last valid position per row
+    logits = _paged_head(params, cfg, norm, h)
     return (logits, PagedKV(k=ks, v=vs),
             occs if occupancy is not None else None,
             ssms if cfg.ssm_state else None)

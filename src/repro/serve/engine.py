@@ -27,6 +27,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ModelConfig
@@ -564,11 +565,15 @@ class ContinuousServeEngine:
         # metrics-window trims that keep the request history bounded
         self._total_tokens = 0
         self._total_requests = 0
-        self._total_finished = 0
         # speculative counters (monotonic, like total_tokens: clear_history
         # never resets them, so fleet-level acceptance tracking stays exact)
         self._spec_drafted = 0
         self._spec_accepted = 0
+        # work counters (monotonic): decode dispatches, the live rows they
+        # carried, and the prompt positions prefill computed
+        self._decode_steps = 0
+        self._decode_rows = 0
+        self._prefill_tokens = 0
         self._draft_rho = float(scfg.draft_rho)
         # rho epoch: bumped by set_target_rho so prefix-cache registration
         # can be gated to pages filled entirely at the current taus
@@ -594,17 +599,22 @@ class ContinuousServeEngine:
             logits, pools, occ, ssm = self._step_decode(
                 params, pools, ssm, occ, tables, lengths, toks, live, policy
             )
-            sliced = logits[..., : self.cfg.vocab]
-            if sample:
-                nxt = sample_tokens(sliced, temps, top_ks, top_ps, seeds, stp)
-            else:
-                nxt = jnp.argmax(sliced, axis=-1).astype(jnp.int32)
+            nxt = self._pick(logits, sample, temps, top_ks, top_ps, seeds, stp)
             return (pools, ssm, occ, lengths + 1, nxt[:, None], stp + 1), nxt
 
         (pools, ssm, occ, _, _, _), toks = jax.lax.scan(
             body, (pools, ssm, occ, lengths, tokens, steps), None, length=self.scfg.decode_window
         )
         return pools, ssm, occ, toks
+
+    def _pick(self, logits, sample: bool, temps, top_ks, top_ps, seeds, steps):
+        """The step's tokens from its logits, under the ``sample`` scope:
+        ``argmax`` for all-greedy batches, ``sample_tokens`` otherwise."""
+        with jax.named_scope("sample"):
+            sliced = logits[..., : self.cfg.vocab]
+            if sample:
+                return sample_tokens(sliced, temps, top_ks, top_ps, seeds, steps)
+            return jnp.argmax(sliced, axis=-1).astype(jnp.int32)
 
     def _step_decode(self, params, pools, ssm, occ, tables, lengths, tokens, live, policy):
         """One model step: the shard_map-wrapped TP path or the plain one.
@@ -664,11 +674,7 @@ class ContinuousServeEngine:
                 logits, p_, o_, s_ = self._step_decode(
                     params, p_, s_, o_, tables, lens_, toks_, live, draft_policy
                 )
-                sliced = logits[..., : self.cfg.vocab]
-                if sample:
-                    nxt = sample_tokens(sliced, temps, top_ks, top_ps, seeds, stp)
-                else:
-                    nxt = jnp.argmax(sliced, axis=-1).astype(jnp.int32)
+                nxt = self._pick(logits, sample, temps, top_ks, top_ps, seeds, stp)
                 return (p_, s_, o_, lens_ + 1, nxt[:, None], stp + 1), nxt
 
             (pools, ssm, occ, _, _, _), draft_toks = jax.lax.scan(
@@ -687,11 +693,7 @@ class ContinuousServeEngine:
                     d["params"], d["cfg"], d["layout"], dp, tables, lens_, toks_,
                     occupancy=None, ssm=None, live=live, policy=draft_policy,
                 )
-                sliced = logits[..., : self.cfg.vocab]
-                if sample:
-                    nxt = sample_tokens(sliced, temps, top_ks, top_ps, seeds, stp)
-                else:
-                    nxt = jnp.argmax(sliced, axis=-1).astype(jnp.int32)
+                nxt = self._pick(logits, sample, temps, top_ks, top_ps, seeds, stp)
                 return (dp, lens_ + 1, nxt[:, None], stp + 1), nxt
 
             (dpools, _, _, _), draft_toks = jax.lax.scan(
@@ -708,11 +710,7 @@ class ContinuousServeEngine:
             logits, p_, o_, s_ = self._step_decode(
                 params, p_, s_, o_, tables, lens_, tok_in[:, None], live, policy
             )
-            sliced = logits[..., : self.cfg.vocab]
-            if sample:
-                nxt = sample_tokens(sliced, temps, top_ks, top_ps, seeds, stp)
-            else:
-                nxt = jnp.argmax(sliced, axis=-1).astype(jnp.int32)
+            nxt = self._pick(logits, sample, temps, top_ks, top_ps, seeds, stp)
             return (p_, s_, o_, lens_ + 1, stp + 1), nxt
 
         (pools, ssm, occ, _, _), tgt_toks = jax.lax.scan(
@@ -757,11 +755,8 @@ class ContinuousServeEngine:
         logits, pools, occ, ssm = self._step_prefill(
             params, pools, ssm, occ, tables, start, tokens, n_valid, fresh, policy
         )
-        sliced = logits[..., : self.cfg.vocab]
-        if sample:  # a request's FIRST token is sampled at step index 0
-            next_tok = sample_tokens(sliced, temps, top_ks, top_ps, seeds, jnp.zeros_like(start))
-        else:
-            next_tok = jnp.argmax(sliced, axis=-1).astype(jnp.int32)
+        # a request's FIRST token is sampled at step index 0
+        next_tok = self._pick(logits, sample, temps, top_ks, top_ps, seeds, jnp.zeros_like(start))
         return pools, ssm, occ, next_tok
 
     def _copy_impl(self, pools, occ, src, dst):
@@ -932,36 +927,46 @@ class ContinuousServeEngine:
     def step(self) -> list[Request]:
         """One engine tick: admissions, then one batched prefill chunk (all
         admitted prompts at once) OR one decode batch (alternating when
-        both are pending).  Returns newly finished requests."""
-        self._tick += 1
-        self._metrics_ver += 1
-        self._drain_copies()  # forks queued since the last jitted call
-        admitted = self.sched.admit_ready()
-        for req in admitted:
-            req.rho_epoch = self._rho_epoch
-        policy = self._current_policy()
-        if self.bundle.admit_compute:
-            # admission-computed slot state (whisper cross-KV): one encoder
-            # run per admitted request, writing its slot row.  Re-admission
-            # after eviction recomputes the same bits, so replay is exact.
-            for req in admitted:
-                dev_inputs = {k: jnp.asarray(v)[None] for k, v in req.inputs.items()}
-                self.slot_state = self._admit(
-                    self.params, self.slot_state, np.int32(req.slot), dev_inputs, policy
-                )
-        prefill_reqs = self.sched.prefill_candidates()
-        ready = self.sched.decode_rows()
-        finished: list[Request] = []
-        if prefill_reqs and (not ready or self._tick % 2 == 1):
-            finished += self._prefill_step(prefill_reqs, policy)
-        elif ready:
-            if self._spec_k:
-                finished += self._spec_step(ready, policy)
-            else:
-                finished += self._decode_step(ready, policy)
-        in_use = sum(a.num_pages - 1 - a.free_pages for a in self.allocators.values())
-        self._peak_pages_in_use = max(self._peak_pages_in_use, in_use)
-        return finished
+        both are pending).  Returns newly finished requests.
+
+        Each phase runs under a host span (``engine.step``, ``engine.admit``,
+        ``engine.prepare``, ``engine.dispatch``, ``engine.wait``,
+        ``engine.emit``; ``engine.copy``, ``engine.spill`` and
+        ``engine.restore`` wherever those happen), which a profiler trace
+        records on the device trace's clock; with no profiler running a
+        span costs a flag check."""
+        with TraceAnnotation("engine.step"):
+            self._tick += 1
+            self._metrics_ver += 1
+            self._drain_copies()  # forks queued since the last jitted call
+            with TraceAnnotation("engine.admit"):
+                admitted = self.sched.admit_ready()
+                for req in admitted:
+                    req.rho_epoch = self._rho_epoch
+                policy = self._current_policy()
+                if self.bundle.admit_compute:
+                    # admission-computed slot state (whisper cross-KV): one
+                    # encoder run per admitted request, writing its slot row.
+                    # Re-admission after eviction recomputes the same bits,
+                    # so replay is exact.
+                    for req in admitted:
+                        dev_inputs = {k: jnp.asarray(v)[None] for k, v in req.inputs.items()}
+                        self.slot_state = self._admit(
+                            self.params, self.slot_state, np.int32(req.slot), dev_inputs, policy
+                        )
+            prefill_reqs = self.sched.prefill_candidates()
+            ready = self.sched.decode_rows()
+            finished: list[Request] = []
+            if prefill_reqs and (not ready or self._tick % 2 == 1):
+                finished += self._prefill_step(prefill_reqs, policy)
+            elif ready:
+                if self._spec_k:
+                    finished += self._spec_step(ready, policy)
+                else:
+                    finished += self._decode_step(ready, policy)
+            in_use = sum(a.num_pages - 1 - a.free_pages for a in self.allocators.values())
+            self._peak_pages_in_use = max(self._peak_pages_in_use, in_use)
+            return finished
 
     def run_until_complete(self, max_steps: int = 1_000_000) -> list[Request]:
         """Step until the queue and every slot drain (or ``max_steps``),
@@ -1014,7 +1019,10 @@ class ContinuousServeEngine:
         # aggregation across metrics-window trims stays exact
         out["total_tokens"] = self._total_tokens
         out["total_requests"] = self._total_requests
-        out["total_finished"] = self._total_finished
+        out["decode_steps"] = self._decode_steps
+        out["decode_rows"] = self._decode_rows
+        out["prefill_tokens"] = self._prefill_tokens
+        out["prefix_linked_tokens"] = self.sched.prefix_linked_tokens
         # NOTE: no "sheds" key here — shedding is admission control, which
         # only the router performs; its metrics() carries the counter (the
         # engine used to export a hardcoded 0 stub; see docs/OPERATIONS.md)
@@ -1024,7 +1032,6 @@ class ContinuousServeEngine:
             out["speculative"] = {
                 "k": self._spec_k,
                 "mode": "cross" if self._draft is not None else "self",
-                "draft_rho": self._draft_rho,
                 # monotonic (clear_history never resets them)
                 "drafted": drafted,
                 "accepted": accepted,
@@ -1032,8 +1039,6 @@ class ContinuousServeEngine:
             }
         else:
             out["speculative"] = None
-        out["free_pages"] = {k: a.free_pages for k, a in self.allocators.items()}
-        out["pages_in_use"] = {k: a.num_pages - 1 - a.free_pages for k, a in self.allocators.items()}
         out["peak_pages_in_use"] = self._peak_pages_in_use
         out["prefix_cache"] = self.prefix_cache.stats() if self.prefix_cache else None
         if self.host_store is not None:
@@ -1056,18 +1061,7 @@ class ContinuousServeEngine:
             out["host_tier"] = None
         out["cache_bytes"] = self.pools.bytes() if self.pools is not None else 0
         out["cache_bytes_per_shard"] = self.pools.shard_bytes() if self.pools is not None else 0
-        out["state_bytes"] = self.state_bytes()
-        out["tp"] = self.mesh.shape["model"] if self.mesh is not None else 1
         out["queue_depth"] = self.sched.queue_depth
-        if self.occupancy is not None:
-            # fraction of live KV positions over the whole pool (unwritten
-            # pages are initialised all-live, so this is an upper bound that
-            # tightens as the pool fills)
-            flat = [np.asarray(v) for v in jax.tree_util.tree_leaves(self.occupancy)]
-            total = sum(a.size for a in flat)
-            out["kv_occupancy_live"] = float(sum(a.sum() for a in flat)) / max(total, 1)
-        else:
-            out["kv_occupancy_live"] = None
         self._metrics_cache = (self._metrics_ver, out)
         return out
 
@@ -1089,17 +1083,18 @@ class ContinuousServeEngine:
         copies = self.sched.pending_copies
         if not copies:
             return
-        self.sched.pending_copies = []
-        n = 1
-        while n < len(copies):
-            n *= 2
-        src = np.zeros((n,), np.int32)
-        dst = np.zeros((n,), np.int32)
-        for i, (s, d) in enumerate(copies):
-            src[i], dst[i] = s, d
-        self.pools, self.occupancy = self._copy(
-            self.pools, self.occupancy, jnp.asarray(src), jnp.asarray(dst)
-        )
+        with TraceAnnotation("engine.copy"):
+            self.sched.pending_copies = []
+            n = 1
+            while n < len(copies):
+                n *= 2
+            src = np.zeros((n,), np.int32)
+            dst = np.zeros((n,), np.int32)
+            for i, (s, d) in enumerate(copies):
+                src[i], dst[i] = s, d
+            self.pools, self.occupancy = self._copy(
+                self.pools, self.occupancy, jnp.asarray(src), jnp.asarray(dst)
+            )
 
     # --- host page tier -----------------------------------------------------
     def _tier_meta(self) -> dict:
@@ -1125,20 +1120,21 @@ class ContinuousServeEngine:
         contents are final; page counts are bucketed to powers of two
         (padding gathers the trash page, sliced off after the fetch) so
         retraces stay logarithmic in table size."""
-        self._drain_copies()
-        data = {}
-        for kind, table in req.tables.items():
-            if not table:
-                continue
-            pages = np.zeros((_pow2(len(table)),), np.int32)
-            pages[: len(table)] = table
-            fetched = jax.device_get(
-                self._extract(self.pools, self.occupancy, jnp.asarray(pages), kind=kind)
-            )
-            data[kind] = jax.tree_util.tree_map(lambda a: a[:, : len(table)], fetched)
-        if not data:
-            return None
-        return {"data": data, "meta": self._tier_meta()}
+        with TraceAnnotation("engine.spill"):
+            self._drain_copies()
+            data = {}
+            for kind, table in req.tables.items():
+                if not table:
+                    continue
+                pages = np.zeros((_pow2(len(table)),), np.int32)
+                pages[: len(table)] = table
+                fetched = jax.device_get(
+                    self._extract(self.pools, self.occupancy, jnp.asarray(pages), kind=kind)
+                )
+                data[kind] = jax.tree_util.tree_map(lambda a: a[:, : len(table)], fetched)
+            if not data:
+                return None
+            return {"data": data, "meta": self._tier_meta()}
 
     def _restore_payload(self, payload: dict, tables: dict[str, list[int]]) -> None:
         """Scheduler restore hook (host -> device): upload a spilled payload
@@ -1148,32 +1144,34 @@ class ContinuousServeEngine:
         Under TP each K/V leaf is ``device_put`` with its pool's KV-head
         sharding, so every restored page slice lands on its owning shard;
         occupancy payloads are per-position and land replicated."""
-        self._drain_copies()
-        for kind, dst in tables.items():
-            data = payload["data"].get(kind)
-            if data is None or not dst:
-                continue
-            n = _pow2(len(dst))
-            dpad = np.zeros((n,), np.int32)  # padding scatters to the trash page
-            dpad[: len(dst)] = dst
-            padded = jax.tree_util.tree_map(lambda a: _pad_pages(a, n), data)
-            if self.mesh is not None:
-                from repro.launch.sharding import paged_payload_shardings
+        with TraceAnnotation("engine.restore"):
+            self._drain_copies()
+            for kind, dst in tables.items():
+                data = payload["data"].get(kind)
+                if data is None or not dst:
+                    continue
+                n = _pow2(len(dst))
+                dpad = np.zeros((n,), np.int32)  # padding scatters to the trash page
+                dpad[: len(dst)] = dst
+                padded = jax.tree_util.tree_map(lambda a: _pad_pages(a, n), data)
+                if self.mesh is not None:
+                    from repro.launch.sharding import paged_payload_shardings
 
-                padded = jax.device_put(padded, paged_payload_shardings(padded, self.mesh))
-            self.pools, self.occupancy = self._insert(
-                self.pools, self.occupancy, jnp.asarray(dpad), padded, kind=kind
-            )
+                    padded = jax.device_put(padded, paged_payload_shardings(padded, self.mesh))
+                self.pools, self.occupancy = self._insert(
+                    self.pools, self.occupancy, jnp.asarray(dpad), padded, kind=kind
+                )
 
     def _spill_prefix_page(self, page: int) -> Optional[dict]:
         """PrefixCache write-behind hook: fetch ONE cached "full"-kind
         page's content, shaped exactly like a one-page request spill so the
         standard restore hook uploads it."""
-        self._drain_copies()
-        fetched = jax.device_get(
-            self._extract(self.pools, self.occupancy, jnp.asarray(np.array([page], np.int32)), kind="full")
-        )
-        return {"data": {"full": fetched}, "meta": self._tier_meta()}
+        with TraceAnnotation("engine.spill"):
+            self._drain_copies()
+            fetched = jax.device_get(
+                self._extract(self.pools, self.occupancy, jnp.asarray(np.array([page], np.int32)), kind="full")
+            )
+            return {"data": {"full": fetched}, "meta": self._tier_meta()}
 
     def _adoptable(self, snap: dict) -> bool:
         """Can this engine restore a snapshot spilled by another replica?
@@ -1189,7 +1187,6 @@ class ContinuousServeEngine:
 
     def _finish(self, req: Request) -> None:
         req.finish_time = time.perf_counter()
-        self._total_finished += 1
         self.sched.finish(req)
 
     def _tables_for(self, reqs: list[Request]) -> dict[str, jnp.ndarray]:
@@ -1209,81 +1206,99 @@ class ContinuousServeEngine:
         """One jitted call caches a chunk for EVERY admitted prompt; rows
         live at their engine slots so hybrid SSM state stays aligned.
         Shared-prefix rows start at their first uncached position."""
-        # incremental sharing (vLLM-style): link pages peers registered
-        # since admission — a same-tick burst of identical prompts dedupes
-        # here, mid-wave, instead of prefilling every copy to completion.
-        # Requests admitted before a ``set_target_rho`` retarget sit in an
-        # older rho EPOCH: their pages mix taus, so they neither link nor
-        # register cache entries (consistency over reuse).
-        for req in reqs:
-            if req.rho_epoch == self._rho_epoch:
-                self.sched.refresh_prefix(req)
-        reqs = [r for r in reqs if not r.ready]  # fully-cached replay: straight to decode
-        if not reqs:
-            return []
-        b, c = self.scfg.slots, self.scfg.prefill_chunk
-        toks = np.zeros((b, c), np.int32)
-        starts = np.zeros((b,), np.int32)
-        nv = np.zeros((b,), np.int32)
-        fresh = np.zeros((b,), bool)
-        st = sampling_tensors(b)
-        sample = False
-        for req in reqs:
-            chunk = req.replay[req.prefill_pos : req.prefill_pos + c]
-            toks[req.slot, : len(chunk)] = chunk
-            starts[req.slot] = req.prefill_pos
-            nv[req.slot] = len(chunk)
-            fresh[req.slot] = req.prefill_pos == 0
-            if req.prefill_pos + len(chunk) >= len(req.replay) and not req.generated:
-                # this row emits its first token from this call
-                fill_row(st, req.slot, req.params, 0)
-                sample |= req.params.temperature > 0
-        self._drain_copies()
-        tables = self._tables_for(reqs)
-        self.pools, self.slot_state, self.occupancy, next_tok = self._prefill(
-            self.params, self.pools, self.slot_state, self.occupancy, tables,
-            jnp.asarray(starts), jnp.asarray(toks), jnp.asarray(nv), jnp.asarray(fresh),
-            policy, st["temps"], st["top_ks"], st["top_ps"], st["seeds"], sample=sample,
-        )
-        if self._draft is not None:
-            # cross-spec: the draft caches the same chunk through the same
-            # tables (evict + replay rebuilds both pools this way)
-            self._draft["pools"] = self._draft_prefill(
-                self._draft["pools"], tables, jnp.asarray(starts), jnp.asarray(toks),
-                jnp.asarray(nv), self._draft_policy(policy),
+        with TraceAnnotation("engine.prepare"):
+            # incremental sharing (vLLM-style): link pages peers registered
+            # since admission — a same-tick burst of identical prompts
+            # dedupes here, mid-wave, instead of prefilling every copy to
+            # completion.  Requests admitted before a ``set_target_rho``
+            # retarget sit in an older rho EPOCH: their pages mix taus, so
+            # they neither link nor register cache entries (consistency
+            # over reuse).
+            for req in reqs:
+                if req.rho_epoch == self._rho_epoch:
+                    self.sched.refresh_prefix(req)
+            reqs = [r for r in reqs if not r.ready]  # fully-cached replay: straight to decode
+            if not reqs:
+                return []
+            b, c = self.scfg.slots, self.scfg.prefill_chunk
+            toks = np.zeros((b, c), np.int32)
+            starts = np.zeros((b,), np.int32)
+            nv = np.zeros((b,), np.int32)
+            fresh = np.zeros((b,), bool)
+            st = sampling_tensors(b)
+            sample = False
+            emits = False  # some row emits its first token from this call
+            for req in reqs:
+                chunk = req.replay[req.prefill_pos : req.prefill_pos + c]
+                toks[req.slot, : len(chunk)] = chunk
+                starts[req.slot] = req.prefill_pos
+                nv[req.slot] = len(chunk)
+                fresh[req.slot] = req.prefill_pos == 0
+                if req.prefill_pos + len(chunk) >= len(req.replay) and not req.generated:
+                    fill_row(st, req.slot, req.params, 0)
+                    sample |= req.params.temperature > 0
+                    emits = True
+            self._drain_copies()
+            tables = self._tables_for(reqs)
+            args = (jnp.asarray(starts), jnp.asarray(toks), jnp.asarray(nv))
+            fresh = jnp.asarray(fresh)
+        with TraceAnnotation("engine.dispatch"):
+            self.pools, self.slot_state, self.occupancy, next_tok = self._prefill(
+                self.params, self.pools, self.slot_state, self.occupancy, tables, *args, fresh,
+                policy, st["temps"], st["top_ks"], st["top_ps"], st["seeds"], sample=sample,
             )
-        finished: list[Request] = []
-        for req in reqs:
-            took = int(nv[req.slot])
-            req.prefill_pos += took
-            req.cache_len = req.prefill_pos
-            if req.rho_epoch == self._rho_epoch:
-                self.sched.register_prefix(req)  # pages -> cache as each fills
-            if req.prefill_pos < len(req.replay):
-                continue
-            req.ready = True
-            if req.generated:  # re-admitted after eviction: resume, don't resample
-                req.pending_token = req.generated[-1]
-                continue
-            tok = int(next_tok[req.slot])
-            req.generated.append(tok)
-            self._total_tokens += 1
-            req.pending_token = tok
-            req.first_token_time = time.perf_counter()
-            if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
-                self._finish(req)
-                finished.append(req)
-        return finished
+            if self._draft is not None:
+                # cross-spec: the draft caches the same chunk through the
+                # same tables (evict + replay rebuilds both pools this way)
+                self._draft["pools"] = self._draft_prefill(
+                    self._draft["pools"], tables, *args, self._draft_policy(policy),
+                )
+        self._prefill_tokens += int(nv.sum())
+        if emits:
+            # one read of the first tokens; a chunk that completes no
+            # prompt never waits for the device
+            with TraceAnnotation("engine.wait"):
+                next_tok = np.asarray(next_tok)
+        with TraceAnnotation("engine.emit"):
+            finished: list[Request] = []
+            for req in reqs:
+                took = int(nv[req.slot])
+                req.prefill_pos += took
+                req.cache_len = req.prefill_pos
+                if req.rho_epoch == self._rho_epoch:
+                    self.sched.register_prefix(req)  # pages -> cache as each fills
+                if req.prefill_pos < len(req.replay):
+                    continue
+                req.ready = True
+                if req.generated:  # re-admitted after eviction: resume, don't resample
+                    req.pending_token = req.generated[-1]
+                    continue
+                tok = int(next_tok[req.slot])
+                req.generated.append(tok)
+                self._total_tokens += 1
+                req.pending_token = tok
+                req.first_token_time = time.perf_counter()
+                if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
+                    self._finish(req)
+                    finished.append(req)
+            return finished
 
-    def _decode_step(self, ready: list[Request], policy) -> list[Request]:
-        window = self.scfg.decode_window
+    def _decode_rows_for(self, ready: list[Request], new_tokens: int, logs=None) -> list[Request]:
+        """Rows that hold pages for their next ``new_tokens`` cache entries
+        (``sched.grow`` may evict peers, and the pool growth may reclaim
+        and spill prefix pages)."""
         rows: list[Request] = []
         for req in ready:
-            if req.slot is not None and self.sched.grow(req, window):
+            log = [] if logs is not None else None
+            if req.slot is not None and self.sched.grow(req, new_tokens, log=log):
                 rows.append(req)
-        rows = [r for r in rows if r.slot is not None]  # grow() may evict peers
-        if not rows:
-            return []
+                if logs is not None:
+                    logs[req.rid] = log
+        return [r for r in rows if r.slot is not None]  # grow() may evict peers
+
+    def _decode_inputs(self, rows: list[Request]):
+        """Host arrays of one decode dispatch: lengths, pending tokens, live
+        mask and the per-row sampling tensors, then the page tables."""
         b = self.scfg.slots
         lens = np.zeros((b,), np.int32)
         toks = np.zeros((b, 1), np.int32)
@@ -1297,26 +1312,40 @@ class ContinuousServeEngine:
             fill_row(st, req.slot, req.params, len(req.generated))
             sample |= req.params.temperature > 0
         self._drain_copies()
-        self.pools, self.slot_state, self.occupancy, win_tok = self._decode(
-            self.params, self.pools, self.slot_state, self.occupancy, self._tables_for(rows),
-            jnp.asarray(lens), jnp.asarray(toks), jnp.asarray(live), policy,
-            st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),
-            sample=sample,
-        )
-        win_tok = np.asarray(win_tok)  # [W, B]
-        finished = []
-        for req in rows:
-            for w in range(window):
-                tok = int(win_tok[w, req.slot])
-                req.cache_len += 1
-                req.generated.append(tok)
-                self._total_tokens += 1
-                req.pending_token = tok
-                if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
-                    self._finish(req)
-                    finished.append(req)
-                    break  # surplus window tokens are discarded
-        return finished
+        args = (self._tables_for(rows), jnp.asarray(lens), jnp.asarray(toks), jnp.asarray(live))
+        return args, st, sample
+
+    def _decode_step(self, ready: list[Request], policy) -> list[Request]:
+        window = self.scfg.decode_window
+        with TraceAnnotation("engine.prepare"):
+            rows = self._decode_rows_for(ready, window)
+            if not rows:
+                return []
+            args, st, sample = self._decode_inputs(rows)
+        with TraceAnnotation("engine.dispatch"):
+            self.pools, self.slot_state, self.occupancy, win_tok = self._decode(
+                self.params, self.pools, self.slot_state, self.occupancy, *args, policy,
+                st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),
+                sample=sample,
+            )
+        self._decode_steps += 1
+        self._decode_rows += len(rows)
+        with TraceAnnotation("engine.wait"):
+            win_tok = np.asarray(win_tok)  # [W, B]
+        with TraceAnnotation("engine.emit"):
+            finished = []
+            for req in rows:
+                for w in range(window):
+                    tok = int(win_tok[w, req.slot])
+                    req.cache_len += 1
+                    req.generated.append(tok)
+                    self._total_tokens += 1
+                    req.pending_token = tok
+                    if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
+                        self._finish(req)
+                        finished.append(req)
+                        break  # surplus window tokens are discarded
+            return finished
 
     def _spec_step(self, ready: list[Request], policy) -> list[Request]:
         """One speculative tick: reserve pages for the verify scan's k + 1
@@ -1326,58 +1355,45 @@ class ContinuousServeEngine:
         the accepted length.  Rows that finish mid-span skip the truncate —
         ``_finish`` releases their pages wholesale."""
         k = self._spec_k
-        rows: list[Request] = []
-        logs: dict[int, list] = {}
-        for req in ready:
-            log: list = []
-            if req.slot is not None and self.sched.grow(req, k + 1, log=log):
-                rows.append(req)
-                logs[req.rid] = log
-        rows = [r for r in rows if r.slot is not None]  # grow() may evict peers
-        if not rows:
-            return []
-        b = self.scfg.slots
-        lens = np.zeros((b,), np.int32)
-        toks = np.zeros((b, 1), np.int32)
-        live = np.zeros((b,), bool)
-        st = sampling_tensors(b)
-        sample = False
-        for req in rows:
-            lens[req.slot] = req.cache_len
-            toks[req.slot, 0] = req.pending_token
-            live[req.slot] = True
-            fill_row(st, req.slot, req.params, len(req.generated))
-            sample |= req.params.temperature > 0
-        self._drain_copies()
-        dpools = self._draft["pools"] if self._draft is not None else None
-        self.pools, self.slot_state, self.occupancy, dpools, tgt_toks, m = self._spec(
-            self.params, self.pools, self.slot_state, self.occupancy, dpools, self._tables_for(rows),
-            jnp.asarray(lens), jnp.asarray(toks), jnp.asarray(live),
-            policy, self._draft_policy(policy),
-            st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),
-            sample=sample, k=k,
-        )
-        if self._draft is not None:
-            self._draft["pools"] = dpools
-        tgt_toks = np.asarray(tgt_toks)  # [k+1, B]
-        m = np.asarray(m)  # [B]
-        finished: list[Request] = []
-        for req in rows:
-            mi = int(m[req.slot])
-            self._spec_drafted += k
-            self._spec_accepted += mi
-            done = False
-            for j in range(mi + 1):  # the target's tokens, in stream order
-                tok = int(tgt_toks[j, req.slot])
-                req.cache_len += 1
-                req.generated.append(tok)
-                self._total_tokens += 1
-                req.pending_token = tok
-                if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
-                    self._finish(req)
-                    finished.append(req)
-                    done = True
-                    break  # surplus accepted tokens are discarded
-            if not done:
-                self.sched.truncate(req, req.cache_len, logs.get(req.rid))
-        return finished
+        with TraceAnnotation("engine.prepare"):
+            logs: dict[int, list] = {}
+            rows = self._decode_rows_for(ready, k + 1, logs)
+            if not rows:
+                return []
+            args, st, sample = self._decode_inputs(rows)
+        with TraceAnnotation("engine.dispatch"):
+            dpools = self._draft["pools"] if self._draft is not None else None
+            self.pools, self.slot_state, self.occupancy, dpools, tgt_toks, m = self._spec(
+                self.params, self.pools, self.slot_state, self.occupancy, dpools, *args,
+                policy, self._draft_policy(policy),
+                st["temps"], st["top_ks"], st["top_ps"], st["seeds"], jnp.asarray(st["steps"]),
+                sample=sample, k=k,
+            )
+            if self._draft is not None:
+                self._draft["pools"] = dpools
+        self._decode_steps += 1
+        self._decode_rows += len(rows)
+        with TraceAnnotation("engine.wait"):
+            tgt_toks = np.asarray(tgt_toks)  # [k+1, B]
+            m = np.asarray(m)  # [B]
+        with TraceAnnotation("engine.emit"):
+            finished: list[Request] = []
+            for req in rows:
+                mi = int(m[req.slot])
+                self._spec_drafted += k
+                self._spec_accepted += mi
+                done = False
+                for j in range(mi + 1):  # the target's tokens, in stream order
+                    tok = int(tgt_toks[j, req.slot])
+                    req.cache_len += 1
+                    req.generated.append(tok)
+                    self._total_tokens += 1
+                    req.pending_token = tok
+                    if len(req.generated) >= req.max_new_tokens or tok in req.stop_ids:
+                        self._finish(req)
+                        finished.append(req)
+                        done = True
+                        break  # surplus accepted tokens are discarded
+                if not done:
+                    self.sched.truncate(req, req.cache_len, logs.get(req.rid))
+            return finished

@@ -218,6 +218,7 @@ class ContinuousScheduler:
         self.restores = 0  # re-admissions served from the host tier
         self.restored_pages = 0
         self.tier_replays = 0  # re-admissions that fell back to prompt replay
+        self.prefix_linked_tokens = 0  # prompt tokens linked from the prefix cache
         self.queue: deque[Request] = deque()
         self.active: dict[int, Request] = {}  # slot -> request
         self._free_slots = list(range(slots - 1, -1, -1))
@@ -521,6 +522,7 @@ class ContinuousScheduler:
                     if req.shared_tokens:
                         self.prefix_cache.hits += 1
                         self.prefix_cache.pages_shared += req.shared_tokens // self.page_size
+                        self.prefix_linked_tokens += req.shared_tokens
             self.queue.popleft()
             req.slot = self._free_slots.pop()
             req.admit_stamp = next(self._stamps)
@@ -643,7 +645,9 @@ class ContinuousScheduler:
                     relinked = 0
             req.prefill_pos = new_pos
             req.cache_len = new_pos
-            req.shared_tokens = max(req.shared_tokens, new_pos)
+            if new_pos > req.shared_tokens:
+                self.prefix_linked_tokens += new_pos - req.shared_tokens
+                req.shared_tokens = new_pos
             req.ready = new_pos >= len(req.replay)
             if req.ready:  # fully-cached replay: resume decode directly
                 req.pending_token = req.generated[-1]
@@ -847,22 +851,13 @@ def pct(xs: list, q: float):
 
 
 def summarize(requests: list[Request]) -> dict:
-    """Aggregate latency/SLO metrics over finished requests (cancelled
-    requests are counted separately and excluded from the latency/SLO
-    percentiles — they never completed)."""
+    """Tokens, latency percentiles and evictions over finished requests
+    (cancelled requests never completed and are left out)."""
     done = [r for r in requests if r.done and not r.cancelled]
     lats = sorted(r.latency() for r in done)
-    ttfts = sorted(t for t in (r.ttft() for r in done) if t is not None)
-    tokens = sum(len(r.generated) for r in done)
-    slo_known = [r.slo_met() for r in done if r.slo_met() is not None]
     return {
-        "finished": len(done),
-        "cancelled": sum(1 for r in requests if r.cancelled),
-        "tokens": tokens,
+        "tokens": sum(len(r.generated) for r in done),
         "p50_latency_s": pct(lats, 0.50),
         "p99_latency_s": pct(lats, 0.99),
-        "p50_ttft_s": pct(ttfts, 0.50),
-        "p99_ttft_s": pct(ttfts, 0.99),
         "evictions": sum(r.evictions for r in done),
-        "slo_met_frac": (sum(slo_known) / len(slo_known)) if slo_known else None,
     }

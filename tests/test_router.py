@@ -479,7 +479,7 @@ class TestRealEngines:
         m1 = eng.metrics()
         assert m1 is eng.metrics()  # memoized: no state change, same object
         assert m1["total_tokens"] == sum(len(r.generated) for r in reqs)
-        assert m1["total_requests"] == 2 and m1["total_finished"] == 2
+        assert m1["total_requests"] == 2 and all(r.done for r in reqs)
         # shedding is a router decision; the engine never sheds and must not
         # report a vestigial always-zero counter (it shadowed the real one)
         assert "sheds" not in m1
@@ -524,11 +524,11 @@ class TestRhoEpoch:
         _, _, prompts, _ = setup
         eng = make_engine(cfg, params)
         eng.generate(prompts[:2], max_new_tokens=2)
-        assert eng.prefix_cache.stats()["cached_pages"] > 0
+        assert eng.prefix_cache.cached_pages > 0
         epoch = eng._rho_epoch
         eng.set_target_rho(0.5)
         assert eng._rho_epoch == epoch + 1
-        assert eng.prefix_cache.stats()["cached_pages"] == 0  # old-taus pages gone
+        assert eng.prefix_cache.cached_pages == 0  # old-taus pages gone
         eng.set_target_rho(0.5)  # idempotent: same rho, same epoch
         assert eng._rho_epoch == epoch + 1
 

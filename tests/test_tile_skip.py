@@ -337,8 +337,8 @@ class TestEngineTileSkip:
         cfg, params, prompts = setup
         eng = make_engine(cfg, params, tile_skip=True)
         eng.generate(prompts, max_new_tokens=8)
-        m = eng.metrics()
-        assert m["kv_occupancy_live"] is not None and m["kv_occupancy_live"] < 1.0
+        bits = [np.asarray(v) for v in jax.tree_util.tree_leaves(eng.occupancy)]
+        assert bits and not all(b.all() for b in bits)  # some position went dead
 
     def test_rho_zero_matches_legacy_dense_engine(self, setup):
         """At rho=0 every tau is 0, no position is ever dead, and the tiled
