@@ -108,13 +108,19 @@ def sample_tokens(
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    order = jnp.argsort(-scaled, axis=-1)  # descending
-    sorted_l = jnp.take_along_axis(scaled, order, axis=-1)
+    # descending values by a value-only sort: every filter below needs the
+    # sorted values, never their indices, and a per-element gather along the
+    # vocabulary is slow on a TPU.  Unstable, so it stays one operand (a
+    # stable sort carries an iota): the comparator only ties -0.0 with +0.0
+    # (and NaNs), which no comparison or sum below tells apart.
+    sorted_l = -jax.lax.sort(-scaled, dimension=1, is_stable=False)
 
-    # top-k: keep logits >= the k-th largest (runtime per-row k)
+    # top-k: keep logits >= the k-th largest (runtime per-row k), picked by
+    # a select-and-reduce rather than a gather
     k = jnp.where(top_ks > 0, top_ks, v)
     k = jnp.clip(k, 1, v)
-    kth = jnp.take_along_axis(sorted_l, (k - 1)[:, None], axis=-1)  # [B, 1]
+    at_k = jax.lax.broadcasted_iota(jnp.int32, sorted_l.shape, 1) == (k - 1)[:, None]
+    kth = jnp.max(jnp.where(at_k, sorted_l, -jnp.inf), axis=-1, keepdims=True)  # [B, 1]
     masked = jnp.where(scaled >= kth, scaled, NEG_INF)
 
     # top-p over the top-k-filtered distribution: keep the tokens whose

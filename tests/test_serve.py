@@ -109,5 +109,10 @@ class TestBaselineSampling:
 
     def test_stop_set_truncates(self, engine):
         full = engine.generate([[1, 2]], max_new_tokens=8)[0]
-        got = engine.generate([[1, 2]], sampling=SamplingParams(stop={full[1], full[4]}, max_new_tokens=8))[0]
-        assert got == full[:2]
+        stops = {full[1], full[4]}
+        got = engine.generate([[1, 2]], sampling=SamplingParams(stop=stops, max_new_tokens=8))[0]
+        # the first emitted stop token ends generation and is kept; full[1]
+        # is a stop, so the cut falls at index 1 or earlier (a stop token
+        # may already appear before it)
+        cut = next(j for j, t in enumerate(full) if t in stops)
+        assert cut <= 1 and got == full[: cut + 1]
